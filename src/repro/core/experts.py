@@ -123,8 +123,11 @@ def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
     # (shard-destination binning), so capacity >= top_k means a lone
     # token can never overflow its own dispatch
     cap = max(cap, top_k)
-    # upper clamp: a bin can never receive more than every assignment
-    return max(8, round_up(min(cap, num_tokens * top_k), 8))
+    # upper clamp: a bin can never receive more than every assignment, so
+    # cap at t*k rounded DOWN to the 8-row alignment (rounding up after
+    # the clamp would overshoot it), but never below the top_k floor
+    hi = max(8, round_up(top_k, 8), (num_tokens * top_k) // 8 * 8)
+    return min(max(8, round_up(cap, 8)), hi)
 
 
 def dropped_pairs(keep: Array, valid: Optional[Array], shape) -> Array:
@@ -527,7 +530,10 @@ def _measured_crossover() -> Optional[dict]:
     ``benchmarks/bench_decode_backends.py --out`` and carries the bank
     shape it was measured on; ``select_backend`` only trusts it for calls
     with the SAME (num_experts, top_k) — any other shape falls back to
-    the ~E/k heuristic. Which source decided is logged once."""
+    the ~E/k heuristic. An artifact measured on another platform than
+    ``jax.default_backend()`` (or naming none) is ignored with a warning:
+    a CPU timing says nothing about a TPU's break-even. Which source
+    decided is logged once."""
     global _measured
     if _measured is not _UNLOADED:
         return _measured
@@ -549,10 +555,16 @@ def _measured_crossover() -> Optional[dict]:
             continue
         try:
             with open(path) as f:
-                cx = (json.load(f) or {}).get("crossover")
+                art = json.load(f) or {}
         except (OSError, ValueError) as e:
             log.warning("ignoring unreadable bench file %s: %s", path, e)
             continue
+        platform = jax.default_backend()
+        if art.get("platform") != platform:
+            log.warning("ignoring bench file %s: measured on platform %r, "
+                        "running on %r", path, art.get("platform"), platform)
+            continue
+        cx = art.get("crossover")
         if cx and "gather_max_tokens" in cx:
             log.info("backend break-even: MEASURED crossover from %s "
                      "(gather wins to %s tokens at E=%s, k=%s)", path,

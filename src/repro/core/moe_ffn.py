@@ -118,7 +118,6 @@ def cmoe_ffn_local(x: Array, p: dict, cfg, mesh, *,
     k_row: optional (B, S) int32 per-token effective k — sharded like
     `valid` and threaded to the gate inside each shard's local dispatch.
     """
-    from repro.compat import shard_map
     from repro.distributed.policy import _dp  # local import, no cycle
     cm = cfg.cmoe
     n_r = cm.num_routed
@@ -197,10 +196,13 @@ def cmoe_ffn_local(x: Array, p: dict, cfg, mesh, *,
         pm = jax.lax.pmean(probs.mean(0), "data")
         return y, load, pm, dropped
 
-    out_specs = (x_spec, P(None), P(None), P(None))
-    y, load, pm, dropped = shard_map(
+    # replication is not checked: the body mixes per-shard and
+    # psum-replicated outputs
+    out_specs = (x_spec, P(None), P(None), P())
+    y, load, pm, dropped = jax.shard_map(
         local_ffn, mesh=mesh,
-        in_specs=(x_spec, p_specs, v_spec, v_spec), out_specs=out_specs)(
+        in_specs=(x_spec, p_specs, v_spec, v_spec), out_specs=out_specs,
+        check_vma=False)(
             x, {k: p[k] for k in
                 ("shared", "routed", "router", "u", "bias")
                 if k in p}, valid, k_row)

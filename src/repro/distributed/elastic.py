@@ -16,7 +16,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 from repro.distributed.sharding import param_specs, to_shardings
 
@@ -33,7 +33,8 @@ def plan_elastic_mesh(num_devices: int, *, model_parallel: int = 16,
         mp //= 2
     dp = num_devices // mp
     used = devices[:dp * mp]
-    return jax.make_mesh((dp, mp), ("data", "model"), devices=used)
+    return jax.make_mesh((dp, mp), ("data", "model"), devices=used,
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def reshard_tree(tree, mesh: Mesh):

@@ -46,7 +46,7 @@ def _kernel(eidx_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
     # the zeroed accumulator, matching the zeroed gate downstream
     @pl.when(eidx_ref[i] < num_experts)
     def _():
-        x = x_ref[...]                               # (1, d)
+        x = x_ref[0]                                 # (1, d)
         g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
         if activation == "swiglu":
@@ -58,7 +58,7 @@ def _kernel(eidx_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 def moe_gather(xf: jax.Array, eidx: jax.Array, wg: jax.Array, wu: jax.Array,
@@ -72,7 +72,12 @@ def moe_gather(xf: jax.Array, eidx: jax.Array, wg: jax.Array, wu: jax.Array,
     slab 0, coalescing consecutive dead fetches), run no FLOPs, and
     output a zero row. wg/wu: (E, d, m); wd: (E, m, d) -> (T*k, d)
     per-assignment expert outputs (pre gate-weight combine). Caller pads
-    m to a block_m multiple."""
+    m to a block_m multiple.
+
+    Token rows and output rows move as (1, 1, d) blocks of (T, 1, d) /
+    (T*k, 1, d) views: the TPU lowering needs a block's two minor dims to
+    be (8, 128)-aligned or whole, which a (1, d) row of a (T, d) array is
+    not (and a squeezed row dim is refused the same way)."""
     t, d = xf.shape
     n_e = wg.shape[0]
     m = wg.shape[2]
@@ -89,17 +94,18 @@ def moe_gather(xf: jax.Array, eidx: jax.Array, wg: jax.Array, wu: jax.Array,
         num_scalar_prefetch=1,
         grid=(n, m // block_m),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, k, e: (i // top_k, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, k, e: (i // top_k, 0, 0)),
             pl.BlockSpec((1, d, block_m), lambda i, k, e: (slab(e, i), 0, k)),
             pl.BlockSpec((1, d, block_m), lambda i, k, e: (slab(e, i), 0, k)),
             pl.BlockSpec((1, block_m, d), lambda i, k, e: (slab(e, i), k, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, k, e: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, k, e: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, activation=activation, num_experts=n_e),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), xf.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), xf.dtype),
         interpret=interpret,
-    )(eidx, xf, wg, wu, wd)
+    )(eidx, xf.reshape(t, 1, d), wg, wu, wd)
+    return out.reshape(n, d)

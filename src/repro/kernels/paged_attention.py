@@ -19,11 +19,12 @@ length and point at the trash block 0 anyway).
 
 Two families share the pattern:
 
-``paged_attn_decode`` — GQA. Grid (B, KH, nblk), nblk innermost
-    (sequential on TPU -> scratch carries). Each step attends one
-    (bs, hd) physical block with the `grp = H // KH` query heads that
-    share kv head h; supports the per-layer sliding window as a
-    prefetched scalar (traced per-layer values allowed).
+``paged_attn_decode`` — GQA. Grid (B, nblk), nblk innermost
+    (sequential on TPU -> scratch carries). Each step DMAs one whole
+    (bs, KH, hd) physical block and attends each kv head's slab with
+    the `grp = H // KH` query heads that share it; supports the
+    per-layer sliding window as a prefetched scalar (traced per-layer
+    values allowed).
 
 ``mla_paged_decode`` — MLA absorbed decode. The pool holds the latent
     (bs, r) + rope-key (bs, dr) blocks; scores are
@@ -47,9 +48,10 @@ NEG_INF = -1e30
 
 
 def _gqa_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, scale: float, block_size: int):
+                m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
+                kv_heads: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
@@ -57,30 +59,34 @@ def _gqa_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                  # (grp, hd)
-    k = k_ref[0, :, 0, :]                            # (bs, hd)
-    v = v_ref[0, :, 0, :]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     kpos = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1)               # (1, bs) logical pos
     pos = pos_ref[b]
     win = win_ref[0]
     mask = kpos <= pos
-    mask &= jnp.where(win > 0, kpos > pos - win, True)
-    s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    # (win <= 0) | ...: Mosaic cannot lower a select between bool vectors
+    mask &= (win <= 0) | (kpos > pos - win)
+    # one DMA brought the whole (bs, KH, hd) block; each kv head's
+    # (bs, hd) slab is a strided VMEM read, attended by its grp queries
+    for h in range(kv_heads):
+        q = q_ref[0, h]                              # (grp, hd)
+        k = k_ref[0, :, h, :]                        # (bs, hd)
+        v = v_ref[0, :, h, :]
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attn_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -90,34 +96,38 @@ def paged_attn_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     (nblocks, bs, KH, hd) block pools; table: (B * nblk,) int32 flattened
     block tables; pos: (B,) int32 per-lane last valid logical index;
     window: (1,) int32 sliding window (0 = full). Returns (B, KH, grp,
-    hd). The table/pos/window arrive as scalar prefetch so each kv tile's
-    DMA is issued from table[b * nblk + j] before the body runs."""
+    hd). The table/pos/window arrive as scalar prefetch so each block's
+    DMA is issued from table[b * nblk + j] before the body runs. A K/V
+    block spans all KV heads: the TPU lowering needs a block's two minor
+    dims to be (8, 128)-aligned or whole, and a one-head (1, hd) slice
+    of the (KH, hd) minor dims is neither."""
     b, kh, grp, hd = q.shape
     bs = k_pool.shape[1]
     nblk = table.shape[0] // b
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kh, nblk),
+        grid=(b, nblk),
         in_specs=[
-            pl.BlockSpec((1, 1, grp, hd),
-                         lambda bb, h, j, tbl, ps, w: (bb, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda bb, h, j, tbl, ps, w:
-                         (tbl[bb * nblk + j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda bb, h, j, tbl, ps, w:
-                         (tbl[bb * nblk + j], 0, h, 0)),
+            pl.BlockSpec((1, kh, grp, hd),
+                         lambda bb, j, tbl, ps, w: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, bs, kh, hd),
+                         lambda bb, j, tbl, ps, w:
+                         (tbl[bb * nblk + j], 0, 0, 0)),
+            pl.BlockSpec((1, bs, kh, hd),
+                         lambda bb, j, tbl, ps, w:
+                         (tbl[bb * nblk + j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, grp, hd),
-                               lambda bb, h, j, tbl, ps, w: (bb, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, kh, grp, hd),
+                               lambda bb, j, tbl, ps, w: (bb, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((grp, 1), jnp.float32),
-            pltpu.VMEM((grp, 1), jnp.float32),
-            pltpu.VMEM((grp, hd), jnp.float32),
+            pltpu.VMEM((kh, grp, 1), jnp.float32),
+            pltpu.VMEM((kh, grp, 1), jnp.float32),
+            pltpu.VMEM((kh, grp, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_gqa_kernel, scale=scale, block_size=bs),
+        functools.partial(_gqa_kernel, scale=scale, block_size=bs,
+                          kv_heads=kh),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, grp, hd), q.dtype),
         interpret=interpret,

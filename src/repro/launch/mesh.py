@@ -13,6 +13,7 @@ Logical mapping (see repro/distributed/sharding.py):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,7 +32,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, found {len(devices)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(see repro/launch/dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -39,7 +41,8 @@ def make_host_mesh(model_parallel: int = 1):
     n = len(jax.devices())
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axis_names(mesh) -> tuple[str, ...]:

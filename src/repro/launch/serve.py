@@ -55,6 +55,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core.convert import convert_dense_model
 from repro.core.experts import BACKENDS, microbatch_backend
 from repro.data import make_calibration_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import ServingEngine, make_requests, make_sampler
 
@@ -69,7 +70,7 @@ def parse_sxayez(tag: str) -> CMoEConfig:
     return CMoEConfig(num_experts=e, num_shared=s, top_k=a)
 
 
-def serve_continuous(model, params, args) -> int:
+def serve_continuous(model, params, args):
     """Continuous-batching mode: Poisson arrivals, per-request lengths.
     --max-prefill-tokens bounds each step's prefill compute: prompts
     longer than the budget are split into per-step chunks interleaved
@@ -288,10 +289,13 @@ def serve_continuous(model, params, args) -> int:
     if report.slot_reuse == 0 and args.requests > args.batch:
         print("[continuous] warning: no slot was recycled (arrivals too "
               "spread out?)")
-    return 0
+    return report
 
 
-def main(argv=None):
+def run(argv=None):
+    """The CLI's body. Returns (model, report): the served model and, in
+    --continuous mode, the engine's EngineReport (None in static mode),
+    so an in-process caller can check what was served."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--smoke", action="store_true")
@@ -392,6 +396,8 @@ def main(argv=None):
                          "mode: a correctness gate (e.g. with --paged "
                          "--parity), not a speed run")
     args = ap.parse_args(argv)
+    print(f"[jax] {jax.default_backend()} backend, compile cache "
+          f"{enable_compile_cache()}")
 
     if args.continuous and args.smoke and not args.cmoe:
         # exercise the per-micro-batch backend policy by default: without
@@ -457,7 +463,7 @@ def main(argv=None):
             ctx = activation_sharding(mesh, seq_shard=False,
                                       capacity_factor=args.capacity_factor)
         with ctx:
-            return serve_continuous(model, params, args)
+            return model, serve_continuous(model, params, args)
 
     rng = np.random.default_rng(args.seed)
     prompts = rng.integers(0, cfg.vocab_size,
@@ -523,6 +529,11 @@ def main(argv=None):
                                make_sampler(args.temperature, args.seed))
             tput = args.batch * steps / max(dt, 1e-9)
             print(f"decode[{be}]: {tput:.1f} tok/s ({dt*1000:.1f} ms total)")
+    return model, None
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

@@ -212,8 +212,9 @@ def test_select_backend_measured_crossover(tmp_path, monkeypatch):
     import json
     from repro.core import experts as ex
     f = tmp_path / "bench.json"
-    f.write_text(json.dumps({"crossover": {
-        "gather_max_tokens": 16, "num_experts": 160, "top_k": 6}}))
+    f.write_text(json.dumps({"platform": jax.default_backend(),
+                             "crossover": {"gather_max_tokens": 16,
+                                           "num_experts": 160, "top_k": 6}}))
     monkeypatch.setenv("REPRO_DECODE_BENCH", str(f))
     ex._reset_measured_crossover()
     try:
@@ -240,6 +241,40 @@ def test_select_backend_measured_crossover(tmp_path, monkeypatch):
                               top_k=6) == "gather"
         assert select_backend(64, None, "decode", num_experts=160,
                               top_k=6) == "gather"
+    finally:
+        ex._reset_measured_crossover()
+
+
+@pytest.mark.parametrize("platform", ["foreign", "missing", "current"])
+def test_measured_crossover_checks_platform(tmp_path, monkeypatch, caplog,
+                                            platform):
+    """An artifact measured on another platform (or naming none) is
+    ignored and logged; one from the running platform is used."""
+    import json
+    import logging
+    from repro.core import experts as ex
+    art = {"crossover": {"gather_max_tokens": 16, "num_experts": 160,
+                         "top_k": 6}}
+    if platform != "missing":
+        here = jax.default_backend()
+        art["platform"] = here if platform == "current" else \
+            ("cpu" if here != "cpu" else "tpu")
+    f = tmp_path / "bench.json"
+    f.write_text(json.dumps(art))
+    monkeypatch.setenv("REPRO_DECODE_BENCH", str(f))
+    ex._reset_measured_crossover()
+    try:
+        with caplog.at_level(logging.WARNING, logger="repro.experts"):
+            cx = ex._measured_crossover()
+        if platform == "current":
+            assert cx == art["crossover"]
+            assert select_backend(64, None, "decode", num_experts=160,
+                                  top_k=6) == "grouped_xla"
+        else:
+            assert cx is None
+            assert "measured on platform" in caplog.text
+            assert select_backend(64, None, "decode", num_experts=160,
+                                  top_k=6) == "gather"
     finally:
         ex._reset_measured_crossover()
 

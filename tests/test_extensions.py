@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from conftest import make_batch
 from repro.config import override
@@ -125,7 +126,8 @@ def test_moe_local_dispatch_matches_global_single_device():
     cfg = override(get_smoke_config("deepseek-v2-236b"), dtype="float32")
     cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
         cfg.moe, capacity_factor=4.0, num_shared=0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     p = init_moe_ffn(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
     y1, _ = moe_ffn(x, p, cfg)

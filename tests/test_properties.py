@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings  # noqa: E402
+from hypothesis import strategies as st  # noqa: E402
 
 from repro.core.clustering import assign_sinkhorn, balanced_kmeans
 from repro.core.profiling import atopk_mask
@@ -148,6 +149,7 @@ def test_routed_experts_width_invariant_all_backends(s, seed):
 @settings(**SET)
 @given(t=st.integers(8, 100), e=st.integers(2, 8),
        factor=st.floats(0.2, 2.0))
+@example(t=9, e=2, factor=2.0)
 def test_capacity_bounds(t, e, factor):
     c = expert_capacity(t, e, 1, factor)
     assert 8 <= c <= max(t, 8)

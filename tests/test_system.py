@@ -106,3 +106,39 @@ def test_converted_model_trains(qwen_smoke):
     gn = sum(float(jnp.sum(x.astype(jnp.float32) ** 2))
              for x in jax.tree.leaves(g))
     assert np.isfinite(gn) and gn > 0
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing is
+    overridden; otherwise the cache is the one fixed, git-ignored
+    directory in the checkout. Nothing is compiled, so nothing is
+    written."""
+    from pathlib import Path
+    from repro.launch.compile_cache import enable_compile_cache
+    repo = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+
+def test_chip_smoke_refuses_without_tpu(capsys):
+    """Off a TPU the chip smoke exits nonzero before doing any work and
+    prints no result line."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert jax.default_backend() != "tpu"
+    assert mod.main() != 0
+    assert '"ok"' not in capsys.readouterr().out
