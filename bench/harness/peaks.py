@@ -1,0 +1,20 @@
+"""Published peaks of each accelerator the benchmark may run on, keyed by
+JAX's ``device_kind``. A kind that is not here is an error, never a
+default: a roofline against a guessed peak is no measurement."""
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+    # 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+    "TPU v5 lite": {"bf16_flops_per_s": 197e12,
+                    "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9,
+                    "source": "Google Cloud TPU v5e documentation"},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
