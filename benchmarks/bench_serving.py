@@ -32,8 +32,8 @@ max_slots decode plus a padded prefill micro-batch), and TPOT p95 no
 worse than 1.25x.
 
 `--out [FILE]` (default BENCH_serving.json) writes every section's
-metrics — goodput, TTFT/TPOT percentiles, compute_utilization,
-overlap_occupancy, overlap on vs off — as JSON next to the printed
+metrics — goodput, TTFT/TPOT percentiles, compute_utilization, overlap
+on vs off — as JSON next to the printed
 report, so the committed baseline tracks the same numbers the gates
 read.
 
@@ -101,7 +101,6 @@ def _metrics(rep) -> dict:
         "tpot_p50_s": round(rep.tpot_p50_s, 5),
         "tpot_p95_s": round(rep.tpot_p95_s, 5),
         "compute_utilization": round(rep.compute_utilization, 4),
-        "overlap_occupancy": round(rep.overlap_occupancy, 4),
         "dropped_pairs": rep.dropped_pairs,
     }
 
@@ -250,8 +249,7 @@ def bench_hol(args, results: dict) -> int:
               f"{max(r.decode_gaps_s) * 1e3:7.1f} ms, goodput "
               f"{r.goodput:7.1f} tok/s, {r.steps} steps, mean TTFT "
               f"{r.mean_ttft_steps:.1f}, util "
-              f"{r.compute_utilization * 100:.0f}%, overlap "
-              f"{r.overlap_occupancy * 100:.0f}%, dropped "
+              f"{r.compute_utilization * 100:.0f}%, dropped "
               f"{r.dropped_pairs}")
     results["hol"] = {"unchunked": _metrics(un), "chunked": _metrics(ch),
                       "overlapped": _metrics(ov)}
@@ -282,8 +280,7 @@ def bench_hol(args, results: dict) -> int:
           f"{ov.compute_utilization * 100:.0f}% "
           f"({'up' if ov_util else 'NOT up'}), TPOT p95 "
           f"{ch.tpot_p95_s * 1e3:.1f} -> {ov.tpot_p95_s * 1e3:.1f} ms "
-          f"({'held' if ov_p95 else 'REGRESSED'}), occupancy "
-          f"{ov.overlap_occupancy * 100:.0f}%")
+          f"({'held' if ov_p95 else 'REGRESSED'})")
     ok = ok and ov_ok
     if args.cmoe:
         bc = ch.backend_counts
@@ -646,9 +643,8 @@ def main(argv=None):
     ap.add_argument("--out", nargs="?", const="BENCH_serving.json",
                     default=None, metavar="FILE",
                     help="write per-section metrics (goodput, TTFT/TPOT "
-                         "percentiles, compute utilization, overlap "
-                         "occupancy) as JSON — default file "
-                         "BENCH_serving.json")
+                         "percentiles, compute utilization) as JSON — "
+                         "default file BENCH_serving.json")
     args = ap.parse_args(argv)
 
     rc = 0

@@ -115,7 +115,7 @@ if [[ "${1:-}" != "--fast" ]]; then
     # the overlapped engine (token identity + compute-utilization gates,
     # soft under --no-gate); --out refreshes the committed
     # BENCH_serving.json baseline (goodput, TTFT/TPOT percentiles,
-    # compute utilization, overlap occupancy per section)
+    # compute utilization per section)
     python benchmarks/bench_serving.py --requests 8 --cmoe --samples 2 \
         --no-gate --out
 fi

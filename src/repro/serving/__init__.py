@@ -79,17 +79,17 @@ batch lane busy on mixed traffic. Three pieces, three contracts:
     token streams (schedule-invariant sampling + per-token capacity
     contract). Returns an ``EngineReport``: goodput, step-clock TTFT and
     wall-clock ttft_p50/p95_s (stamped at EMISSION, so the overlap lag
-    is included), TPOT p50/p95 completion-gap percentiles next to
-    dispatch-gap percentiles (under overlap, dispatch gaps measure host
-    issue rate; completion gaps what a client observes),
-    overlap_occupancy (fraction of dispatches issued while the previous
-    step was in flight), compute utilization (live/padded tokens), the
+    is included), TPOT p50/p95 completion-gap percentiles (what a client
+    observes), compute utilization (live/padded tokens), the
     k-weighted active-pair utilization, per-tier latency via
     ``tier_metrics()``, the per-micro-batch backend log, and — paged —
     the prefix-reuse and overload columns: prefix_hit_rate /
     reused_blocks / cow_copies, gate_deferrals split per cause, and
     preemptions, with the end-of-run pool conservation audit attached
-    as ``pool_audit``.
+    as ``pool_audit``. Each phase of the overlapped loop, and each
+    garbage collection during a run, is a ``jax.profiler`` host span
+    (``engine.*``, ``host.gc``): free with no profile running, and on
+    the device trace's clock when one is.
 
 ACTIVATION TIERS (per-request effective routed top-k). CMoE's converted
 weights serve any routed k in [1, config top_k] — the ``S{s}A{k}E{e}``

@@ -68,37 +68,78 @@ forever. Both policies are token-identity-preserving by construction:
 reuse on == reuse off, preemption-pressured == unpressured, across
 sequential and overlapped dispatch (tests/test_prefix_reuse.py).
 
-Latency telemetry under overlap splits in two. A DISPATCH gap
-(`dispatch_gaps_s`) is the wall time between consecutive fused
-dispatches — how fast the host issues work; it can undercut the device
-step time because issuing never waits on results. A DECODE/COMPLETION
-gap (`decode_gaps_s`, the TPOT percentiles) is the wall time between
-consecutive READBACKS — the inter-token latency a client actually
-observes, including the one-step emission lag. In sequential mode the
-two coincide and both columns carry the same gaps. Either chain only
-continues across steps where a decode lane is live (a chunk-only step is
-a stall no decode token paid, so it breaks the chain), and
-`overlap_occupancy` reports the fraction of dispatches issued while the
-previous step was still in flight — ~1.0 means the device never waited
-on the host. Wall-clock TTFT (`ttft_p50_s`/`ttft_p95_s`, from
-`Request.arrival_t` to `Request.first_token_t`) is stamped at EMISSION,
-so it too includes the lag the client would see.
+Latency telemetry: a DECODE/COMPLETION gap (`decode_gaps_s`, the TPOT
+percentiles) is the wall time between consecutive READBACKS — the
+inter-token latency a client actually observes, including the one-step
+emission lag under overlap. The chain only continues across steps where
+a decode lane is live (a chunk-only step is a stall no decode token
+paid, so it breaks the chain). Wall-clock TTFT (`ttft_p50_s`/
+`ttft_p95_s`, from `Request.arrival_t` to `Request.first_token_t`) is
+stamped at EMISSION, so it too includes the lag the client would see;
+`Request.admit_t`, stamped by the dispatch that first carries the
+request's chunk, splits it into queue wait and prefill.
+
+HOST SPANS. Each phase of the overlapped loop runs inside a
+`jax.profiler` annotation named by the ``SPAN_*`` constants below
+(`engine.step` per loop iteration, then arrivals, plan, rows, stage,
+enqueue, finish, readback, emit; each carries ``step=<n>``, the
+dispatch it belongs to), and every Python garbage collection during
+`run()` is a `host.gc` span. With no profile running each is a ~1 us
+no-op; under `jax.profiler.start_trace` the profiler writes them next to
+the device ops, on one clock, so a device idle gap can be put down to
+what the host was doing in it.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import time
 from collections import Counter, deque
 from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.serving.cache import PagedKVCache, SlotKVCache
 from repro.serving.executor import StepExecutor
 from repro.serving.request import RUNNING, Request
 from repro.serving.sampling import make_sampler
 from repro.serving.scheduler import Scheduler
+
+# host spans (jax.profiler annotations) of the overlapped loop
+SPAN_STEP = "engine.step"          # one loop iteration (step_num=<n>)
+SPAN_ARRIVALS = "engine.arrivals"  # _stamp_arrivals
+SPAN_PLAN = "engine.plan"          # Scheduler.plan_prefill
+SPAN_ROWS = "engine.rows"          # row collection, kv.ensure / kv.commit
+SPAN_STAGE = "engine.stage"        # padded row arrays, table rows, H2D
+SPAN_ENQUEUE = "engine.enqueue"    # the jitted fused step's call
+SPAN_FINISH = "engine.finish"      # promotions, finishes, free_request
+SPAN_READBACK = "engine.readback"  # the host's wait for the step's results
+SPAN_EMIT = "engine.emit"          # emission, TTFT stamps, EOS rollback
+SPAN_GC = "host.gc"                # a Python garbage collection in run()
+
+
+@contextlib.contextmanager
+def _gc_spans():
+    """Mark every Python garbage collection inside the block as a
+    ``host.gc`` span."""
+    open_spans = []
+
+    def on_gc(phase, info):
+        if phase == "start":
+            span = TraceAnnotation(SPAN_GC, generation=info["generation"])
+            span.__enter__()
+            open_spans.append(span)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(on_gc)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(on_gc)
 
 
 @dataclasses.dataclass
@@ -145,19 +186,11 @@ class EngineReport:
     #   charged (sequential decode: max_slots per step; prefill: rows x
     #   padded width; fused: the step's granule-rounded row count) —
     #   live/padded is the engine's compute utilization
-    dispatch_gaps_s: list = dataclasses.field(default_factory=list)
-    #   wall gap between consecutive fused DISPATCHES — host issue rate.
-    #   Under overlap it can undercut the device step time (issuing
-    #   never waits on results); in sequential mode it equals
-    #   decode_gaps_s, where dispatch and completion coincide.
     ttft_s: list = dataclasses.field(default_factory=list)
     #   wall-clock arrival -> first EMITTED token per finished-prefill
     #   request (includes the overlapped engine's one-step emission lag —
     #   what a client would measure, where mean_ttft_steps counts
     #   scheduler steps)
-    overlap_occupancy: float = 0.0  # dispatches issued while the previous
-    #   step was still in flight / total dispatches — ~1.0 means the
-    #   device never waited on host readback (0.0 in sequential mode)
     active_pairs: int = 0           # k-weighted live work: sum over live
     #   tokens of that row's effective routed top-k (its activation
     #   TIER). Two tokens at k=1 and k=6 charge identical LIVE TOKENS but
@@ -296,8 +329,7 @@ class EngineReport:
                 f"{self.ttft_p50_s * 1e3:.1f}/{self.ttft_p95_s * 1e3:.1f} "
                 f"ms, TPOT p50/p95 "
                 f"{self.tpot_p50_s * 1e3:.1f}/{self.tpot_p95_s * 1e3:.1f} "
-                f"ms, overlap occupancy "
-                f"{self.overlap_occupancy * 100:.0f}%, slot busy "
+                f"ms, slot busy "
                 f"{self.slot_busy_frac * 100:.0f}%, peak "
                 f"occupancy {self.peak_occupancy}, slot reuse "
                 f"{self.slot_reuse}, truncated {self.truncated}, pool "
@@ -520,8 +552,6 @@ class ServingEngine:
         self.backend_log = []
         self._decode_gaps: list[float] = []
         self._last_decode_t: Optional[float] = None
-        self._dispatch_gaps: list[float] = []
-        self._last_dispatch_t: Optional[float] = None
         if max_steps is None:
             # every iteration with occupied slots prefills >= 1 prompt
             # token or decodes >= 1 token, so the loop is bounded by
@@ -537,9 +567,16 @@ class ServingEngine:
                 work *= 1 + len(requests)
             max_steps = int(horizon) + work + 16
         self.scheduler.submit(requests)
-        if self.overlap:
-            return self._run_fused(requests, max_steps)
+        with _gc_spans():
+            if self.overlap:
+                return self._run_fused(requests, max_steps)
+            return self._run_sequential(requests, max_steps)
 
+    def _run_sequential(self, requests: list[Request],
+                        max_steps: int) -> EngineReport:
+        """The sequential loop: a prefill micro-batch for the planned
+        chunks, then one full-width decode dispatch, syncing every
+        step."""
         step = 0
         busy = 0
         peak = 0
@@ -571,15 +608,11 @@ class ServingEngine:
                 raise RuntimeError(f"engine made no progress in "
                                    f"{max_steps} steps")
         wall = time.perf_counter() - t0
-        # sequential mode: dispatch and completion coincide, so the
-        # dispatch-gap column carries the same gaps as the decode gaps
         return self._mk_report(requests, step=step, wall=wall, busy=busy,
-                               peak=peak,
-                               dispatch_gaps=list(self._decode_gaps),
-                               overlap_occupancy=0.0)
+                               peak=peak)
 
-    def _mk_report(self, requests, *, step, wall, busy, peak,
-                   dispatch_gaps, overlap_occupancy) -> EngineReport:
+    def _mk_report(self, requests, *, step, wall, busy,
+                   peak) -> EngineReport:
         ttft = [r.first_token_step - r.arrival for r in requests]
         ttft_s = [r.first_token_t - r.arrival_t for r in requests
                   if r.first_token_t >= 0 and r.arrival_t >= 0]
@@ -623,9 +656,7 @@ class ServingEngine:
             padded_pairs=sum(row[2] for row in self.backend_log)
             * self._k_max,
             k_max=self._k_max,
-            dispatch_gaps_s=dispatch_gaps,
             ttft_s=ttft_s,
-            overlap_occupancy=overlap_occupancy,
         )
 
     def _stamp_arrivals(self, requests, step: int) -> None:
@@ -796,6 +827,7 @@ class ServingEngine:
             active += c * int(row_k[i])
             if r.admit_step < 0:
                 r.admit_step = step
+                r.admit_t = time.perf_counter()
             if self.paged:
                 # allocate (from the admission reservation) the blocks
                 # this chunk's write window [cursor, cursor + c) lands in
@@ -871,6 +903,7 @@ class ServingEngine:
             active += int(row_k[r.slot])
             if r.admit_step < 0:
                 r.admit_step = step
+                r.admit_t = time.perf_counter()
             if self.paged:
                 self.kv.ensure(r, r.prefill_pos + 1)
         positions = self.kv.positions()
@@ -958,47 +991,32 @@ class ServingEngine:
         self._inflight = inflight      # _preempt rolls back a victim's
         #                                speculative rows through this
         step = busy = peak = 0
-        n_disp = n_overlapped = 0
         t0 = time.perf_counter()
         while not (sched.all_done() and not inflight):
-            self._stamp_arrivals(requests, step)
-            rec = None
-            if not sched.all_done():
-                rec, slot_tokens, occ = self._dispatch_fused(step,
-                                                             slot_tokens)
-                busy += occ
-                peak = max(peak, occ)
-            if rec is not None:
-                n_disp += 1
-                if inflight:
-                    n_overlapped += 1
-                if rec.running:
-                    now = time.perf_counter()
-                    if self._last_dispatch_t is not None:
-                        self._dispatch_gaps.append(
-                            now - self._last_dispatch_t)
-                    self._last_dispatch_t = now
-                else:
-                    self._last_dispatch_t = None
-                inflight.append(rec)
-            else:
-                self._last_dispatch_t = None
-            # double buffer: with a fresh dispatch in flight, read back
-            # everything OLDER than it (steady state: exactly the
-            # previous step); with nothing dispatched this tick there is
-            # nothing to overlap with, so drain fully
-            while len(inflight) > (1 if rec is not None else 0):
-                self._readback_fused(inflight.popleft(), inflight)
+            with StepTraceAnnotation(SPAN_STEP, step_num=step):
+                with TraceAnnotation(SPAN_ARRIVALS, step=step):
+                    self._stamp_arrivals(requests, step)
+                rec = None
+                if not sched.all_done():
+                    rec, slot_tokens, occ = self._dispatch_fused(
+                        step, slot_tokens)
+                    busy += occ
+                    peak = max(peak, occ)
+                if rec is not None:
+                    inflight.append(rec)
+                # double buffer: with a fresh dispatch in flight, read
+                # back everything OLDER than it (steady state: exactly
+                # the previous step); with nothing dispatched this tick
+                # there is nothing to overlap with, so drain fully
+                while len(inflight) > (1 if rec is not None else 0):
+                    self._readback_fused(inflight.popleft(), inflight)
             step += 1
             if step > max_steps:
                 raise RuntimeError(f"engine made no progress in "
                                    f"{max_steps} steps")
         wall = time.perf_counter() - t0
         return self._mk_report(requests, step=step, wall=wall, busy=busy,
-                               peak=peak,
-                               dispatch_gaps=list(self._dispatch_gaps),
-                               overlap_occupancy=(n_overlapped /
-                                                  max(n_disp, 1)))
+                               peak=peak)
 
     def _dispatch_fused(self, step: int, slot_tokens):
         """Plan, flatten, and dispatch ONE fused ragged micro-batch
@@ -1014,7 +1032,90 @@ class ServingEngine:
         sharing a scatter cell inside one jitted step is the one
         collision the write-before-attend invariant cannot absorb."""
         sched = self.scheduler
-        plan = sched.plan_prefill(step)
+        with TraceAnnotation(SPAN_PLAN, step=step):
+            plan = sched.plan_prefill(step)
+        with TraceAnnotation(SPAN_ROWS, step=step):
+            rows, running, promotions, finishes = self._fused_rows(plan,
+                                                                   step)
+        occupied = len(sched.occupied())
+        if not rows:
+            return None, slot_tokens, occupied
+        with TraceAnnotation(SPAN_STAGE, step=step):
+            n = len(rows)
+            g = self._row_granule
+            rp = -(-n // g) * g
+            base = np.zeros(rp, np.int32)
+            use_prev = np.zeros(rp, bool)
+            slots = np.zeros(rp, np.int32)
+            pos_a = np.zeros(rp, np.int32)
+            rids = np.zeros(rp, np.int32)
+            tidx = np.zeros(rp, np.int32)
+            carry = np.zeros(rp, bool)
+            row_k = np.full(rp, self._k_max, np.int32)
+            for i, row in enumerate(rows):
+                base[i] = row.base
+                use_prev[i] = row.use_prev
+                slots[i] = row.slot
+                pos_a[i] = row.pos
+                rids[i] = row.req.rid
+                tidx[i] = row.tidx
+                carry[i] = row.carry
+                row_k[i] = self._tier_k(row.req)
+            active = int(row_k[:n].sum())
+            # padding rows duplicate row 0 — same scatter cell, same
+            # value, a no-op rewrite — with carry=False so they never
+            # touch the token carry (and their sampled rows are simply
+            # never read); row 0's tier rides along so the padded row_k
+            # vector stays a function of the real rows only
+            base[n:] = base[0]
+            use_prev[n:] = use_prev[0]
+            slots[n:] = slots[0]
+            pos_a[n:] = pos_a[0]
+            rids[n:] = rids[0]
+            tidx[n:] = tidx[0]
+            row_k[n:] = row_k[0]
+            tables = jnp.asarray(self.kv.table_rows(slots)) \
+                if self.paged else None
+            base, use_prev, slots, pos_a, rids, tidx, carry = (
+                jnp.asarray(x)
+                for x in (base, use_prev, slots, pos_a, rids, tidx, carry))
+            row_k = self._row_k_arg(row_k)
+        with TraceAnnotation(SPAN_ENQUEUE, step=step):
+            if self.paged:
+                nxt, slot_tokens, cache, backend, dropped = \
+                    self.executor.step_fused_paged(
+                        self.params, self.kv.cache, base, use_prev,
+                        slot_tokens, slots, tables, pos_a, rids, tidx,
+                        carry, row_k=row_k,
+                        effective_k=self._eff_k(active, n))
+            else:
+                nxt, slot_tokens, cache, backend, dropped = \
+                    self.executor.step_fused(
+                        self.params, self.kv.cache, base, use_prev,
+                        slot_tokens, slots, pos_a, rids, tidx, carry,
+                        row_k=row_k, effective_k=self._eff_k(active, n))
+        # release the staged inputs before the old pool: the device
+        # allocator frees both when the step completes, and freeing the
+        # small inputs after the pool slows each free (~1 ms of device
+        # idle a step on a v5e)
+        del base, use_prev, slots, pos_a, rids, tidx, carry, tables, row_k
+        self.kv.cache = cache
+        with TraceAnnotation(SPAN_FINISH, step=step):
+            for r in promotions:
+                sched.prefill_done(r)
+            for r in finishes:
+                sched.finish(r, step)
+                self.kv.free_request(r)
+        return (_InFlight(step=step, nxt=nxt, dropped=dropped, rows=rows,
+                          running=running, padded=rp, live=n,
+                          backend=backend, active_pairs=active),
+                slot_tokens, occupied)
+
+    def _fused_rows(self, plan, step: int):
+        """(rows, decode rows, promotions, finishes) of one fused step:
+        a decode row per RUNNING lane, then the planned chunks' rows,
+        with positions, dispatch counts and paged blocks advanced."""
+        sched = self.scheduler
         rows: list[_FusedRow] = []
         finishes: list[Request] = []
         promotions: list[Request] = []
@@ -1044,6 +1145,7 @@ class ServingEngine:
             # micro-batch shape exists in this loop
             if r.admit_step < 0:
                 r.admit_step = step
+                r.admit_t = time.perf_counter()
             if self.paged:
                 self.kv.ensure(r, r.prefill_pos + c)
             toks = r.seq_tokens      # prompt, or the preemption replay
@@ -1072,72 +1174,7 @@ class ServingEngine:
                     if full and r.resume_m + 1 < r.max_new:
                         r.truncated = True
                     finishes.append(r)
-        occupied = len(sched.occupied())
-        if not rows:
-            return None, slot_tokens, occupied
-        n = len(rows)
-        g = self._row_granule
-        rp = -(-n // g) * g
-        base = np.zeros(rp, np.int32)
-        use_prev = np.zeros(rp, bool)
-        slots = np.zeros(rp, np.int32)
-        pos_a = np.zeros(rp, np.int32)
-        rids = np.zeros(rp, np.int32)
-        tidx = np.zeros(rp, np.int32)
-        carry = np.zeros(rp, bool)
-        row_k = np.full(rp, self._k_max, np.int32)
-        for i, row in enumerate(rows):
-            base[i] = row.base
-            use_prev[i] = row.use_prev
-            slots[i] = row.slot
-            pos_a[i] = row.pos
-            rids[i] = row.req.rid
-            tidx[i] = row.tidx
-            carry[i] = row.carry
-            row_k[i] = self._tier_k(row.req)
-        active = int(row_k[:n].sum())
-        # padding rows duplicate row 0 — same scatter cell, same value, a
-        # no-op rewrite — with carry=False so they never touch the token
-        # carry (and their sampled rows are simply never read); row 0's
-        # tier rides along so the padded row_k vector stays a function of
-        # the real rows only
-        base[n:] = base[0]
-        use_prev[n:] = use_prev[0]
-        slots[n:] = slots[0]
-        pos_a[n:] = pos_a[0]
-        rids[n:] = rids[0]
-        tidx[n:] = tidx[0]
-        row_k[n:] = row_k[0]
-        if self.paged:
-            tables = self.kv.table_rows(slots)
-            nxt, slot_tokens, cache, backend, dropped = \
-                self.executor.step_fused_paged(
-                    self.params, self.kv.cache, jnp.asarray(base),
-                    jnp.asarray(use_prev), slot_tokens,
-                    jnp.asarray(slots), jnp.asarray(tables),
-                    jnp.asarray(pos_a), jnp.asarray(rids),
-                    jnp.asarray(tidx), jnp.asarray(carry),
-                    row_k=self._row_k_arg(row_k),
-                    effective_k=self._eff_k(active, n))
-        else:
-            nxt, slot_tokens, cache, backend, dropped = \
-                self.executor.step_fused(
-                    self.params, self.kv.cache, jnp.asarray(base),
-                    jnp.asarray(use_prev), slot_tokens,
-                    jnp.asarray(slots), jnp.asarray(pos_a),
-                    jnp.asarray(rids), jnp.asarray(tidx),
-                    jnp.asarray(carry), row_k=self._row_k_arg(row_k),
-                    effective_k=self._eff_k(active, n))
-        self.kv.cache = cache
-        for r in promotions:
-            sched.prefill_done(r)
-        for r in finishes:
-            sched.finish(r, step)
-            self.kv.free_request(r)
-        return (_InFlight(step=step, nxt=nxt, dropped=dropped, rows=rows,
-                          running=running, padded=rp, live=n,
-                          backend=backend, active_pairs=active),
-                slot_tokens, occupied)
+        return rows, running, promotions, finishes
 
     def _readback_fused(self, rec: _InFlight,
                         inflight: "deque[_InFlight]") -> None:
@@ -1145,32 +1182,34 @@ class ServingEngine:
         effects the dispatch speculated past: emission (and wall-clock
         TTFT), the backend_log row (its dropped column is a device scalar
         until here), completion-gap accounting, and EOS finishes."""
-        nxt = np.asarray(rec.nxt)           # the one host sync per step
-        now = time.perf_counter()
-        self.backend_log.append((rec.step, "decode", rec.padded, rec.live,
-                                 rec.backend,
-                                 int(np.asarray(rec.dropped)),
-                                 rec.active_pairs))
-        if rec.running:
-            if self._last_decode_t is not None:
-                self._decode_gaps.append(now - self._last_decode_t)
-            self._last_decode_t = now
-        else:
-            self._last_decode_t = None
-        for i, row in enumerate(rec.rows):
-            if row.kind == "mid" or not row.valid:
-                continue
-            r = row.req
-            tok = int(nxt[i])
-            if row.kind == "first" and r.first_token_step < 0:
-                # a resumed request's "first" row is its replay
-                # completion — the original first-token stamps stand
-                r.first_token_step = rec.step
-                r.first_token_t = now
-            r.generated.append(tok)
-            r.last_token_t = now
-            if r.eos_id is not None and tok == r.eos_id:
-                self._eos_rollback(r, rec.step, inflight)
+        with TraceAnnotation(SPAN_READBACK, step=rec.step):
+            nxt = np.asarray(rec.nxt)       # the one host sync per step
+            dropped = int(np.asarray(rec.dropped))
+        with TraceAnnotation(SPAN_EMIT, step=rec.step):
+            now = time.perf_counter()
+            self.backend_log.append((rec.step, "decode", rec.padded,
+                                     rec.live, rec.backend, dropped,
+                                     rec.active_pairs))
+            if rec.running:
+                if self._last_decode_t is not None:
+                    self._decode_gaps.append(now - self._last_decode_t)
+                self._last_decode_t = now
+            else:
+                self._last_decode_t = None
+            for i, row in enumerate(rec.rows):
+                if row.kind == "mid" or not row.valid:
+                    continue
+                r = row.req
+                tok = int(nxt[i])
+                if row.kind == "first" and r.first_token_step < 0:
+                    # a resumed request's "first" row is its replay
+                    # completion — the original first-token stamps stand
+                    r.first_token_step = rec.step
+                    r.first_token_t = now
+                r.generated.append(tok)
+                r.last_token_t = now
+                if r.eos_id is not None and tok == r.eos_id:
+                    self._eos_rollback(r, rec.step, inflight)
 
     def _eos_rollback(self, r: Request, step: int,
                       inflight: "deque[_InFlight]") -> None:

@@ -60,6 +60,10 @@ class Request:
     admit_step: int = -1              # step the request got its slot
     first_token_step: int = -1        # step the first token was sampled
     arrival_t: float = -1.0           # wall clock the request became due
+    admit_t: float = -1.0             # wall clock of the dispatch that
+    #   first carried one of its prompt chunks (set with admit_step; a
+    #   preempted request keeps it): admit_t - arrival_t is its queue
+    #   wait, first_token_t - admit_t its prefill to first token
     first_token_t: float = -1.0       # wall clock the first token was
     #   EMITTED (host-visible) — under the overlapped engine this lags
     #   the sampling dispatch by one step, which is exactly the latency
@@ -117,6 +121,7 @@ class Request:
         self.admit_step = -1
         self.first_token_step = -1
         self.arrival_t = -1.0
+        self.admit_t = -1.0
         self.first_token_t = -1.0
         self.last_token_t = -1.0
         self.finish_step = -1
