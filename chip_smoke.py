@@ -104,7 +104,7 @@ def compare_kernels(cfg, seed: int = 0):
     bf = jnp.bfloat16
 
     q = jax.random.normal(ks[0], (rows, 1, cfg.num_heads, hd), bf)
-    pools = [jax.random.normal(kk, (SLOTS * nblk + 1, BLOCK, kh, hd), bf)
+    pools = [jax.random.normal(kk, (SLOTS * nblk + 1, BLOCK, kh * hd), bf)
              for kk in ks[1:3]]
     table = jax.random.randint(ks[3], (rows, nblk), 1, SLOTS * nblk + 1)
     pos = jax.random.randint(ks[4], (rows,), 0, nblk * BLOCK)

@@ -20,11 +20,12 @@
 #                      materialized (T*k, d, m) gather copies. Fused
 #                      gate/up/act/down per tile; combine stays in XLA.
 #   paged_attention.py paged_attn_decode: GQA decode attention over the
-#                      paged KV pool. Per-slot block tables + positions
-#                      + window ride scalar prefetch; grid (B, nblk)
-#                      walks each slot's LIVE physical blocks (all KV
-#                      heads per DMA) via the table index_map, masking by
-#                      logical length, with
+#                      lane-dense paged KV pool (nblocks, bs, KH * hd).
+#                      Per-slot block tables + positions + window ride
+#                      scalar prefetch; grid (B, nblk) walks each slot's
+#                      LIVE physical blocks (all KV heads per DMA, read in
+#                      128-lane slices of heads) via the table index_map,
+#                      masking by logical length, with
 #                      online-softmax m/l/acc scratch carried across the
 #                      sequential innermost dim. mla_paged_decode: same
 #                      walk over the latent (cc, cp) pools, scoring

@@ -182,13 +182,14 @@ def paged_attn_decode(q: Array, k_pool: Array, v_pool: Array, *,
                       table: Array, pos: Array, window=0,
                       scale: float) -> Array:
     """GQA paged decode straight off the block pool. q: (B, 1, H, hd);
-    k_pool/v_pool: (nblocks, bs, KH, hd); table: (B, nblk) int32 block
-    tables (0 = trash/unallocated); pos: (B,) int32 last valid logical
-    index per lane; window: int32 scalar sliding window (0 = full; may be
-    traced — it rides scalar prefetch). Returns (B, 1, H, hd). No VJP."""
+    k_pool/v_pool: (nblocks, bs, KH * hd) lane-dense pools; table:
+    (B, nblk) int32 block tables (0 = trash/unallocated); pos: (B,) int32
+    last valid logical index per lane; window: int32 scalar sliding
+    window (0 = full; may be traced — it rides scalar prefetch). Returns
+    (B, 1, H, hd). No VJP."""
     b, s, h, hd = q.shape
     assert s == 1, s
-    kh = k_pool.shape[2]
+    kh = k_pool.shape[-1] // hd
     grp = h // kh
     qg = q[:, 0].reshape(b, kh, grp, hd)
     tbl = table.astype(jnp.int32).reshape(-1)

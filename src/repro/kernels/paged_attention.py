@@ -20,11 +20,13 @@ length and point at the trash block 0 anyway).
 Two families share the pattern:
 
 ``paged_attn_decode`` — GQA. Grid (B, nblk), nblk innermost
-    (sequential on TPU -> scratch carries). Each step DMAs one whole
-    (bs, KH, hd) physical block and attends each kv head's slab with
-    the `grp = H // KH` query heads that share it; supports the
-    per-layer sliding window as a prefetched scalar (traced per-layer
-    values allowed).
+    (sequential on TPU -> scratch carries). The pool is lane-dense,
+    (nblocks, bs, KH * hd): each step DMAs one whole (bs, KH * hd)
+    physical block and walks it in lane slices of n kv heads (128
+    lanes where hd divides 128 and KH allows), each attended by the
+    n * grp query heads that share it, laid block-diagonally over the
+    slice's lanes; supports the per-layer sliding window as a prefetched
+    scalar (traced per-layer values allowed).
 
 ``mla_paged_decode`` — MLA absorbed decode. The pool holds the latent
     (bs, r) + rope-key (bs, dr) blocks; scores are
@@ -38,6 +40,7 @@ autodiff callers must leave off).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +52,7 @@ NEG_INF = -1e30
 
 def _gqa_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
                 m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
-                kv_heads: int):
+                slices: int, width: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -66,22 +69,25 @@ def _gqa_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
     mask = kpos <= pos
     # (win <= 0) | ...: Mosaic cannot lower a select between bool vectors
     mask &= (win <= 0) | (kpos > pos - win)
-    # one DMA brought the whole (bs, KH, hd) block; each kv head's
-    # (bs, hd) slab is a strided VMEM read, attended by its grp queries
-    for h in range(kv_heads):
-        q = q_ref[0, h]                              # (grp, hd)
-        k = k_ref[0, :, h, :]                        # (bs, hd)
-        v = v_ref[0, :, h, :]
+    # one DMA brought the whole (bs, KH * hd) block; each (bs, width)
+    # lane slice holds n kv heads, attended at once by their n * grp
+    # block-diagonal queries: a query row is zero outside its own head's
+    # lanes, so its scores are exact, and of its output row the caller
+    # keeps only those lanes
+    for c in range(slices):
+        q = q_ref[0, c]                              # (n * grp, width)
+        k = k_ref[0, :, c * width:(c + 1) * width]   # (bs, width)
+        v = v_ref[0, :, c * width:(c + 1) * width]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[h]
+        m_prev = m_ref[c]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+        l_ref[c] = l_ref[c] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[c] = acc_ref[c] * corr + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[h] = m_new
+        m_ref[c] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
@@ -93,45 +99,64 @@ def paged_attn_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       table: jax.Array, pos: jax.Array, window: jax.Array,
                       *, scale: float, interpret: bool = True) -> jax.Array:
     """q: (B, KH, grp, hd) grouped queries; k_pool/v_pool:
-    (nblocks, bs, KH, hd) block pools; table: (B * nblk,) int32 flattened
-    block tables; pos: (B,) int32 per-lane last valid logical index;
-    window: (1,) int32 sliding window (0 = full). Returns (B, KH, grp,
-    hd). The table/pos/window arrive as scalar prefetch so each block's
-    DMA is issued from table[b * nblk + j] before the body runs. A K/V
-    block spans all KV heads: the TPU lowering needs a block's two minor
-    dims to be (8, 128)-aligned or whole, and a one-head (1, hd) slice
-    of the (KH, hd) minor dims is neither."""
+    (nblocks, bs, KH * hd) lane-dense block pools (kv head h owns lanes
+    [h * hd, (h + 1) * hd)); table: (B * nblk,) int32 flattened block
+    tables; pos: (B,) int32 per-lane last valid logical index; window:
+    (1,) int32 sliding window (0 = full). Returns (B, KH, grp, hd). The
+    table/pos/window arrive as scalar prefetch so each block's DMA is
+    issued from table[b * nblk + j] before the body runs. A K/V block
+    spans all KV heads: the TPU lowering needs a block's two minor dims
+    to be (8, 128)-aligned or whole. With the heads in the lanes the
+    pool's minor dim is unpadded wherever KH * hd is a multiple of 128,
+    so XLA keeps the pool row-major and passes each layer's slice to the
+    kernel without a relayout copy (a (KH, 64) minor pair pads hd to 128
+    lanes, and XLA transposes the pool to avoid it).
+
+    The body reads the block in slices of n = gcd(KH, 128 // hd) heads
+    (1 where hd >= 128), so a 64-lane head is never sliced out of its
+    vreg: the n heads' queries are laid out block-diagonally here, each
+    in its own head's lanes, and the diagonal blocks of the kernel's
+    (n * grp, n * hd) outputs are the heads' results."""
     b, kh, grp, hd = q.shape
     bs = k_pool.shape[1]
     nblk = table.shape[0] // b
+    n = math.gcd(kh, max(1, 128 // hd))
+    slices, rows, width = kh // n, n * grp, n * hd
+    diag = jnp.eye(n, dtype=bool)[:, None, :, None]      # (n, 1, n, 1)
+    qs = q.reshape(b, slices, n, grp, 1, hd)
+    qbd = jnp.where(diag, qs, jnp.zeros((), q.dtype))    # (.., n, grp, n, hd)
+    qbd = qbd.reshape(b, slices, rows, width)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, nblk),
         in_specs=[
-            pl.BlockSpec((1, kh, grp, hd),
+            pl.BlockSpec((1, slices, rows, width),
                          lambda bb, j, tbl, ps, w: (bb, 0, 0, 0)),
-            pl.BlockSpec((1, bs, kh, hd),
+            pl.BlockSpec((1, bs, kh * hd),
                          lambda bb, j, tbl, ps, w:
-                         (tbl[bb * nblk + j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, kh, hd),
+                         (tbl[bb * nblk + j], 0, 0)),
+            pl.BlockSpec((1, bs, kh * hd),
                          lambda bb, j, tbl, ps, w:
-                         (tbl[bb * nblk + j], 0, 0, 0)),
+                         (tbl[bb * nblk + j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, kh, grp, hd),
+        out_specs=pl.BlockSpec((1, slices, rows, width),
                                lambda bb, j, tbl, ps, w: (bb, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((kh, grp, 1), jnp.float32),
-            pltpu.VMEM((kh, grp, 1), jnp.float32),
-            pltpu.VMEM((kh, grp, hd), jnp.float32),
+            pltpu.VMEM((slices, rows, 1), jnp.float32),
+            pltpu.VMEM((slices, rows, 1), jnp.float32),
+            pltpu.VMEM((slices, rows, width), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gqa_kernel, scale=scale, block_size=bs,
-                          kv_heads=kh),
+                          slices=slices, width=width),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, grp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, slices, rows, width), q.dtype),
         interpret=interpret,
-    )(table, pos, window, q, k_pool, v_pool)
+    )(table, pos, window, qbd, k_pool, v_pool)
+    out = jnp.diagonal(out.reshape(b, slices, n, grp, n, hd),
+                       axis1=2, axis2=4)                 # (.., grp, hd, n)
+    return jnp.moveaxis(out, -1, 2).reshape(b, kh, grp, hd)
 
 
 def _mla_kernel(tbl_ref, pos_ref, qa_ref, qp_ref, cc_ref, cp_ref, o_ref,

@@ -132,7 +132,7 @@ def paged_attn_decode_ref(q: Array, k_pool: Array, v_pool: Array,
     """Materializing oracle for the GQA paged decode kernel: assemble each
     lane's logical view via the table, then masked softmax attention.
 
-    q: (B, KH, grp, hd); k_pool/v_pool: (nblocks, bs, KH, hd);
+    q: (B, KH, grp, hd); k_pool/v_pool: (nblocks, bs, KH * hd);
     table: (B, nblk) int32; pos: (B,) int32; window: () int32 (0 = full).
     Returns (B, KH, grp, hd)."""
     b, kh, grp, hd = q.shape

@@ -313,9 +313,9 @@ def paged_view(pool: Array, table: Array) -> Array:
     trash block, standing in for not-yet-allocated tail entries; whatever
     it holds sits at positions >= the lane's valid length, where the
     ragged/decode masks never look). Returns (B, nblk * bs, ...): the
-    same tensor `gather_slots` used to copy out of a contiguous lane, so
-    the downstream mask/softmax math is shared verbatim with the
-    contiguous path."""
+    lane's tokens in logical order, as `gather_slots` copies them out of
+    a contiguous lane, so the downstream mask/softmax math is shared
+    verbatim with the contiguous path."""
     b, nblk = table.shape
     g = pool[table]                                 # (B, nblk, bs, ...)
     return g.reshape(b, nblk * pool.shape[1], *pool.shape[2:])
@@ -346,13 +346,18 @@ def paged_ragged_attention(q: Array, k_pool: Array, v_pool: Array, *,
                            table: Array, pos: Array,
                            window: Array | int = 0,
                            scale: Optional[float] = None) -> Array:
-    """`ragged_attention` over a paged cache: index the pool by block
-    table into the logical (B, T) view, then run the shared per-slot
+    """`ragged_attention` over a lane-dense paged cache (nblocks, bs,
+    KH * hd): index the pool by block table into the logical (B, T) view,
+    split its lanes into (KH, hd), then run the shared per-slot
     mask/softmax math. Masking is by per-slot logical length (query i of
     lane b attends positions <= pos[b] + i), so trash/unallocated blocks
-    beyond a lane's valid depth are never attended."""
-    k = paged_view(k_pool, table)
-    v = paged_view(v_pool, table)
+    beyond a lane's valid depth are never attended. Only the gathered
+    view is reshaped, never the pool: a reshape of the pool would bring
+    back the relayout the lane-dense layout avoids."""
+    b, t = table.shape[0], table.shape[1] * k_pool.shape[1]
+    hd = q.shape[-1]
+    k = paged_view(k_pool, table).reshape(b, t, -1, hd)
+    v = paged_view(v_pool, table).reshape(b, t, -1, hd)
     return ragged_attention(q, k, v, pos=pos, window=window, scale=scale)
 
 
@@ -425,9 +430,10 @@ def gqa_attention(x: Array, p: dict, cfg, *,
       if kv_cache provided with cache_pos, prefill writes into the cache.
     - decode: x has S=1 and kv_cache + cache_pos given.
     - cross_kv: precomputed encoder K/V (whisper cross-attention).
-    - block_table (B, nblk): kv_cache is a PAGED pool (nblocks, bs, KH,
-      hd) per leaf — writes scatter through the table, reads assemble the
-      logical view per lane (see paged_cache_update / paged_view);
+    - block_table (B, nblk): kv_cache is a lane-dense PAGED pool
+      (nblocks, bs, KH * hd) per leaf — writes scatter through the table,
+      reads assemble the logical view per lane (see paged_cache_update /
+      paged_view);
       use_kernel routes paged DECODE through the Pallas paged-attention
       kernel (no logical view materialized; inference only — no VJP).
     - row_slots (R,): FUSED ragged serving over the contiguous GLOBAL
@@ -462,8 +468,10 @@ def gqa_attention(x: Array, p: dict, cfg, *,
             # paged serving path: write the new K/V through the block
             # table, then attend the table-assembled logical view with
             # the SAME ragged per-slot masks as the contiguous slot path
-            ck = paged_cache_update(ck, k, start, block_table)
-            cv = paged_cache_update(cv, v, start, block_table)
+            ck = paged_cache_update(ck, k.reshape(b, s, -1), start,
+                                    block_table)
+            cv = paged_cache_update(cv, v.reshape(b, s, -1), start,
+                                    block_table)
             new_kv = (ck, cv)
             if s == 1:
                 out = paged_decode_attention(q, ck, cv, table=block_table,

@@ -375,10 +375,13 @@ class Model:
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          abstract=False):
-        """Paged KV pool: the same per-token layout as ``init_cache`` but
-        with the contiguous (B, max_len) slot-lane axes replaced by a flat
-        pool of fixed-size blocks — every leaf is (L, num_blocks,
-        block_size, ...). Lanes address the pool through per-slot block
+        """Paged KV pool: the per-token K/V of ``init_cache`` with the
+        contiguous (B, max_len) slot-lane axes replaced by a flat pool of
+        fixed-size blocks — every leaf is (L, num_blocks, block_size,
+        ...). A GQA leaf folds the kv heads into the lanes, (..., KH *
+        hd): the minor dim stays whole, so XLA keeps the pool row-major
+        and each layer's slice reaches the paged-attention kernel with
+        no relayout copy. Lanes address the pool through per-slot block
         tables (threaded to attention as ``step(block_tables=...)``); by
         the serving engine's convention physical block 0 is the trash
         block that absorbs dummy/spill writes (see
@@ -392,10 +395,8 @@ class Model:
         hd = cfg.resolved_head_dim
 
         def attn_pool(n_layers):
-            return (make((n_layers, num_blocks, block_size,
-                          cfg.num_kv_heads, hd), dt),
-                    make((n_layers, num_blocks, block_size,
-                          cfg.num_kv_heads, hd), dt))
+            shape = (n_layers, num_blocks, block_size, cfg.num_kv_heads * hd)
+            return make(shape, dt), make(shape, dt)
 
         if self.kind == "mla_moe":
             m = cfg.mla

@@ -1,7 +1,8 @@
 """Interpret-mode parity for the paged decode kernel stack: the
 paged-attention kernels vs the materializing reference across fragmented
-pools, recycled-slot-style tables, and block sizes {4, 8, 16}; the gather
-MoE kernel vs `_gather`'s XLA rows on decode shapes."""
+lane-dense pools, recycled-slot-style tables, block sizes {4, 8, 16} and
+the served head widths (hd 64 and 128); the gather MoE kernel vs
+`_gather`'s XLA rows on decode shapes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,20 +37,19 @@ def _fragmented_table(key, b, nblk, num_blocks, pos, bs):
 
 
 def _make_pools(key, num_blocks, bs, kh, hd, dtype):
+    """Lane-dense K/V pools as the model lays them out: (1 + num_blocks,
+    bs, KH * hd), kv head h in lanes [h * hd, (h + 1) * hd)."""
     ks = jax.random.split(key, 2)
-    kp = jax.random.normal(ks[0], (1 + num_blocks, bs, kh, hd), dtype)
-    vp = jax.random.normal(ks[1], (1 + num_blocks, bs, kh, hd), dtype)
+    kp = jax.random.normal(ks[0], (1 + num_blocks, bs, kh * hd), dtype)
+    vp = jax.random.normal(ks[1], (1 + num_blocks, bs, kh * hd), dtype)
     return kp, vp
 
 
-@pytest.mark.parametrize("bs", [4, 8, 16])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("window", [0, 11])
-def test_paged_attn_kernel(bs, dtype, window):
-    b, kh, grp, hd, nblk = 4, 2, 3, 16, 5
+def _check_paged_attn(bs, dtype, window, kh, grp, hd, seed):
+    b, nblk = 4, 5
     h = kh * grp
     num_blocks = b * nblk
-    ks = jax.random.split(jax.random.PRNGKey(bs + window), 4)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (b, 1, h, hd), dtype)
     kp, vp = _make_pools(ks[1], num_blocks, bs, kh, hd, dtype)
     # staggered lengths incl. a fresh lane (pos 0) and a full lane
@@ -64,6 +64,24 @@ def test_paged_attn_kernel(bs, dtype, window):
     np.testing.assert_allclose(np.asarray(out[:, 0], np.float32),
                                np.asarray(exp.reshape(b, h, hd), np.float32),
                                **_tol(dtype))
+
+
+@pytest.mark.parametrize("bs", [4, 8, 16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("window", [0, 11])
+def test_paged_attn_kernel(bs, dtype, window):
+    _check_paged_attn(bs, dtype, window, kh=2, grp=3, hd=16,
+                      seed=bs + window)
+
+
+@pytest.mark.parametrize("kh,grp,hd", [(8, 4, 128), (16, 1, 64)],
+                         ids=["mistral", "qwen"])
+@pytest.mark.parametrize("window", [0, 11])
+def test_paged_attn_kernel_served_widths(kh, grp, hd, window):
+    """The served head widths: one 128-lane head per slice (Mistral-7B,
+    GQA 4:1) and two 64-lane heads per slice (qwen1.5-0.5b, MHA)."""
+    _check_paged_attn(16, jnp.bfloat16, window, kh=kh, grp=grp, hd=hd,
+                      seed=kh + window)
 
 
 def test_paged_attn_kernel_ignores_dead_entries():
@@ -83,8 +101,8 @@ def test_paged_attn_kernel_ignores_dead_entries():
     tb = np.asarray(table)
     for i in range(b):
         live[tb[i, :int(pos[i]) // bs + 1]] = True
-    poison = jnp.where(jnp.asarray(live)[:, None, None, None], kp, 1e4)
-    poison_v = jnp.where(jnp.asarray(live)[:, None, None, None], vp, -1e4)
+    poison = jnp.where(jnp.asarray(live)[:, None, None], kp, 1e4)
+    poison_v = jnp.where(jnp.asarray(live)[:, None, None], vp, -1e4)
     out2 = ops.paged_attn_decode(q, poison, poison_v, table=table, pos=pos,
                                  window=0, scale=hd ** -0.5)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))

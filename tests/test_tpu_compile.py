@@ -15,11 +15,19 @@ result holds a ``tpu_custom_call``. Nothing runs.
 - the whole fused paged serving step of the converted qwen1.5-0.5b with
   kernels on, at a decode-only width (gather) and at the widest fused
   width (grouped), from ``jax.eval_shape`` shapes.
+- the same step at the widths ``qwen05b.chat`` serves (32 lanes of 1536
+  tokens, 16-token blocks, 3073-block pool): no ``copy`` in the optimised
+  HLO may produce a per-layer pool slice or the whole pool. Such a copy
+  is a relayout of the KV pool around ``paged_attn_decode``, which a
+  pool whose minor dims pad to the (8, 128) tile brings in.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -38,6 +46,9 @@ NUM_BLOCKS = SLOTS * NBLK + 1         # + the trash block
 NUM_EXPERTS, NUM_SHARED, TOP_K = 8, 3, 3
 BLOCK_M = 128                         # expert width is padded to this
 RAGGED_BLOCK_C = 128                  # ops.ragged_block_c() on a TPU
+# the qwen05b.chat cell's engine: 32 lanes of 1024 + 512 tokens
+CELL_SLOTS, CELL_NBLK = 32, 1536 // BLOCK
+CELL_NUM_BLOCKS = CELL_SLOTS * CELL_NBLK + 1
 
 
 @pytest.fixture(scope="module")
@@ -75,7 +86,7 @@ def _qwen_cases():
     d = cfg.d_model
     e = NUM_EXPERTS - NUM_SHARED
     m = _round_up(cfg.d_ff // NUM_EXPERTS, BLOCK_M)
-    pool = (NUM_BLOCKS, BLOCK, kh, hd)
+    pool = (NUM_BLOCKS, BLOCK, kh * hd)
     gather_t = 8                     # widest width the policy sends to gather
     p_rows = _round_up(FUSED_WIDTH * TOP_K + e * (RAGGED_BLOCK_C - 1),
                        RAGGED_BLOCK_C)
@@ -126,15 +137,12 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
     assert mem is not None and mem.argument_size_in_bytes > 0
 
 
-@pytest.mark.parametrize("rows,kernels", [
-    (SLOTS, ("paged_attn_decode", "moe_gather")),
-    (FUSED_WIDTH, ("paged_attn_decode", "moe_gmm_ragged"))])
-def test_fused_paged_step_compiles_for_v5e(rows, kernels, one_chip,
-                                           monkeypatch):
+def _compile_fused_paged_step(one_chip, monkeypatch, rows, slots, nblk,
+                              num_blocks):
     """The served step, not just its kernels: params and pool are shapes
     only. The kernel wrappers and the segment GEMM pick their TPU
     branches from the platform, which is the CPU here, so the test
-    steers them to what they pick on a chip."""
+    steers them to what they pick on a chip. Returns (model, compiled)."""
     from repro.core import experts
     from repro.kernels import ops
     from repro.launch.serve import parse_sxayez
@@ -157,11 +165,48 @@ def test_fused_paged_step_compiles_for_v5e(rows, kernels, one_chip,
     backend = ex._backend(rows, "mixed")
     compiled = ex._step_fused_paged.lower(
         place(model.abstract_params()),
-        place(model.init_paged_cache(NUM_BLOCKS, BLOCK, abstract=True)),
-        arg((rows,)), arg((rows,), jnp.bool_), arg((SLOTS,)), arg((rows,)),
-        arg((rows, NBLK)), arg((rows,)), arg((rows,)), arg((rows,)),
+        place(model.init_paged_cache(num_blocks, BLOCK, abstract=True)),
+        arg((rows,)), arg((rows,), jnp.bool_), arg((slots,)), arg((rows,)),
+        arg((rows, nblk)), arg((rows,)), arg((rows,)), arg((rows,)),
         arg((rows,), jnp.bool_), None, backend=backend).compile()
+    return model, compiled
+
+
+@pytest.mark.parametrize("rows,kernels", [
+    (SLOTS, ("paged_attn_decode", "moe_gather")),
+    (FUSED_WIDTH, ("paged_attn_decode", "moe_gmm_ragged"))])
+def test_fused_paged_step_compiles_for_v5e(rows, kernels, one_chip,
+                                           monkeypatch):
+    _, compiled = _compile_fused_paged_step(one_chip, monkeypatch, rows,
+                                            SLOTS, NBLK, NUM_BLOCKS)
     text = compiled.as_text()
     for name in kernels:
         assert f"jit({name})/pallas_call" in text, name
     assert "tpu_custom_call" in text
+
+
+_COPY = re.compile(r"= \w+\[([\d,]*)\]\S* copy\(")
+
+
+@pytest.mark.parametrize("rows", [CELL_SLOTS, CELL_SLOTS + BUDGET])
+def test_fused_paged_step_has_no_pool_copy(rows, one_chip, monkeypatch):
+    """At the cell's widths, at all-lane decode and at the widest fused
+    width: no copy yields a per-layer pool slice (in any shape, so any
+    layout) or the whole pool. The pool stays row-major and each layer's
+    slice feeds ``paged_attn_decode`` as it is."""
+    model, compiled = _compile_fused_paged_step(
+        one_chip, monkeypatch, rows, CELL_SLOTS, CELL_NBLK, CELL_NUM_BLOCKS)
+    text = compiled.as_text()
+    assert "jit(paged_attn_decode)/pallas_call" in text
+    pool = jax.tree.leaves(model.init_paged_cache(CELL_NUM_BLOCKS, BLOCK,
+                                                  abstract=True))[0]
+    layer = math.prod(pool.shape[1:])
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if (m := _COPY.search(line)) and math.prod(
+                  int(d) for d in m.group(1).split(",") if d)
+              in (layer, pool.shape[0] * layer)]
+    assert not copies, "\n".join(copies)
+    # the relayout also held padded slices in temp; one layer's update
+    # of the pool is what the step needs
+    slice_bytes = layer * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * slice_bytes
